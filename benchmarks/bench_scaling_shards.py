@@ -12,8 +12,16 @@ is why the efficiency rows are ``gate=False``). Timing rows are gated
 like every other bench row.
 
 ``sharded_fused`` runs at the census-probed compacted-frontier cap
-(bench_sharded._probed_cap), its natural operating point; the child
-asserts the solve is overflow-free at that cap."""
+(bench_sharded._probed_cap), its natural operating point, as the
+kernel's bitwise jnp twin (the children are CPU-only); the child
+asserts the solve is overflow-free at that cap.
+
+This is a host-forced CPU curve by construction: the children are
+pinned to ``JAX_PLATFORMS=cpu``, so none of them ever needs a chip.
+On a TPU host the module refuses to run — the parent would hold the
+chip while the children start — and the four-chip sharded check is
+``chip_smoke.py --four-chips``, which drives every chip from one
+process."""
 from __future__ import annotations
 
 import os
@@ -32,6 +40,7 @@ STRATEGIES = ("sharded_edge", "sharded_ell", "sharded_fused")
 _CHILD = textwrap.dedent("""
     import os, sys, warnings
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     warnings.filterwarnings("ignore", category=DeprecationWarning)
     n, shards, cap = (int(a) for a in sys.argv[1:4])
     from benchmarks.common import time_fn
@@ -43,7 +52,8 @@ _CHILD = textwrap.dedent("""
         kw = {"frontier_cap": cap} if strategy == "sharded_fused" else {}
         solver = DeltaSteppingSolver(
             g, DeltaConfig(delta=%d, strategy=strategy, n_shards=shards,
-                           pred_mode="none", **kw))
+                           pred_mode="none",
+                           kernel_twin=strategy == "sharded_fused", **kw))
         res = solver.solve(0)
         assert not bool(res.overflow), (strategy, shards, cap)
         t = time_fn(lambda: solver.solve(0).dist, reps=3)
@@ -75,6 +85,13 @@ def _run_width(n: int, shards: int, cap: int) -> dict:
 
 
 def main():
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "bench_scaling_shards is a host-forced CPU curve whose children "
+            "cannot share this process's chip; on TPU run "
+            "`python chip_smoke.py --four-chips` for the sharded check")
     n = scaled(10_000)
     g = watts_strogatz(n, 12, 1e-2, seed=0)
     ref = DeltaSteppingSolver(

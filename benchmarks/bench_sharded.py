@@ -14,13 +14,13 @@ The fused rows run at the strategy's natural operating point: a
 compacted-frontier capacity probed from the instance's bucket census
 (max bucket population + power-of-two headroom), so the per-iteration
 gather is O(cap·deg) instead of O(|V|·deg) — the measured content of
-the fusion claim. The solve is checked overflow-free at that cap. A
-``gate=False`` roofline row reports how far the fused program sits
-from the memory-bandwidth limit (repro.analysis.roofline)."""
+the fusion claim. The solve is checked overflow-free at that cap. Off
+TPU the fused pair runs the kernel's bitwise jnp twin, chosen
+explicitly (``kernel_twin=True``): these rows time XLA:CPU, not the
+kernel."""
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import row, scaled, time_fn
@@ -41,26 +41,6 @@ def _probed_cap(g, dist) -> int:
     finite = d[d < np.iinfo(np.int32).max]
     pop = int(np.bincount(finite // _DELTA).max()) if finite.size else 1
     return min(-(-pop // 128) * 128, g.n_nodes)
-
-
-def _roofline_row(g, cfg, seconds):
-    """gate=False: distance of the measured fused solve from the HBM
-    bandwidth floor of its own compiled program (napkin MODEL_FLOPS:
-    one compare+add per directed edge per settled bucket)."""
-    from repro.analysis import analyze
-    from repro.core import make_backend
-    from repro.core.delta_stepping import _run_one
-
-    backend = make_backend(g, cfg)
-    compiled = _run_one.lower(
-        backend, jnp.int32(0), n=g.n_nodes, packed=False).compile()
-    rep = analyze(compiled, arch="sssp-fused", shape=f"n{g.n_nodes}",
-                  mesh_name="1", n_devices=1,
-                  model_flops=2.0 * g.src.shape[0])
-    x_over_bw = seconds / rep.memory_s if rep.memory_s > 0 else float("inf")
-    row("sharded/fused/roofline", rep.memory_s,
-        f"dominant={rep.dominant};peak_frac={rep.peak_fraction:.3f};"
-        f"measured_over_bw={x_over_bw:.1f}", gate=False)
 
 
 def main():
@@ -85,23 +65,21 @@ def main():
         g, DeltaConfig(delta=_DELTA, strategy="edge",
                        pred_mode="none")).solve(0)
     cap = _probed_cap(g, ref.dist)
-    fused_cfg = None
+    twin = jax.default_backend() != "tpu"
     for strategy in ("fused", "sharded_fused"):
         cfg = DeltaConfig(delta=_DELTA, strategy=strategy, pred_mode="none",
-                          frontier_cap=cap)
+                          frontier_cap=cap, kernel_twin=twin)
         solver = DeltaSteppingSolver(g, cfg)
         res = solver.solve(0)
         assert not bool(res.overflow), (strategy, cap)
         t = time_fn(lambda: solver.solve(0).dist, reps=3)
         times[strategy] = t
         if strategy == "fused":
-            fused_cfg = cfg
             derived = f"cap={cap};vs_ell={times['ell'] / t:.2f}"
         else:
             derived = (f"{tag};cap={cap};"
                        f"vs_fused={times['fused'] / t:.2f}")
         row(f"sharded/{strategy}/solve", t, derived)
-    _roofline_row(g, fused_cfg, times["fused"])
     # batched multi-source through the sharded engine (vmapped shard_map)
     batch = 8
     srcs = np.arange(batch, dtype=np.int32)

@@ -98,4 +98,7 @@ if __name__ == "__main__":
     _root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if _root not in sys.path:
         sys.path.insert(0, _root)
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

@@ -1,12 +1,13 @@
 from repro.analysis.hlo import collective_stats, count_op
 from repro.analysis.roofline import (
-    V5E,
+    PEAKS,
     RooflineReport,
     analyze,
     estimate_model_flops,
     load_reports,
+    peaks,
     save_reports,
 )
 
 __all__ = ["collective_stats", "count_op", "analyze", "RooflineReport",
-           "V5E", "estimate_model_flops", "save_reports", "load_reports"]
+           "PEAKS", "peaks", "estimate_model_flops", "save_reports", "load_reports"]

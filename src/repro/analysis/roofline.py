@@ -1,7 +1,8 @@
 """Roofline-term derivation from a compiled dry-run artifact.
 
-Target hardware: TPU v5e — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI (constants below).
+Chip peaks come from one table, ``PEAKS``, keyed by
+``jax.Device.device_kind``; ``peaks(kind)`` raises for a device that is
+not in it — there is no default chip.
 
 ``compiled.cost_analysis()`` and ``compiled.as_text()`` of an SPMD
 module are per-partition, so with
@@ -27,11 +28,27 @@ import json
 
 from repro.analysis.hlo import collective_stats
 
-V5E = {
-    "peak_flops": 197e12,    # bf16 FLOP/s per chip
-    "hbm_bw": 819e9,         # bytes/s per chip
-    "link_bw": 50e9,         # bytes/s per ICI link
+# Published per-chip peaks. Source: Google Cloud documentation, "TPU
+# v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect (four links of 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,    # bf16 FLOP/s per chip
+        "hbm_bw": 819e9,         # bytes/s per chip
+        "link_bw": 50e9,         # bytes/s per ICI link
+    },
 }
+
+
+def peaks(device_kind: str) -> dict:
+    """The ``PEAKS`` entry of one ``device_kind``; an unknown device is
+    an error, never a borrowed entry."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass
@@ -83,9 +100,13 @@ def _mem_analysis_dict(compiled) -> dict:
 
 
 def analyze(compiled, *, arch: str, shape: str, mesh_name: str,
-            n_devices: int, model_flops: float, hw: dict = V5E,
+            n_devices: int, model_flops: float, device_kind: str,
             note: str = "") -> RooflineReport:
+    """Roofline terms of ``compiled`` against the peaks of the chip
+    ``device_kind`` names (the target chip, which a dry run compiles
+    for on placeholder devices)."""
     from repro.compat import cost_analysis_dict
+    hw = peaks(device_kind)
     cost = cost_analysis_dict(compiled)
     flops_dev = float(cost.get("flops", 0.0))
     bytes_dev = float(cost.get("bytes accessed", 0.0))

@@ -47,6 +47,7 @@ import warnings
 from functools import partial
 from typing import Optional, Sequence, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -604,11 +605,15 @@ class Plan:
 
     def explain(self) -> dict:
         """Plan provenance for logs/telemetry: the resolved operating
-        point plus the tuning record (if any) it came from."""
+        point plus the tuning record (if any) it came from.
+        ``kernel_path`` says how the strategy's Pallas kernels run:
+        'compiled', 'interpret', 'twin', or 'xla' for a strategy
+        without kernels (core.backends ``kernel_path``)."""
         cfg = self.config
         return {
             "delta": cfg.delta,
             "strategy": cfg.strategy,
+            "kernel_path": self.backend.kernel_path,
             "policy": cfg.policy,
             "pred_mode": cfg.pred_mode,
             "frontier_cap": cfg.frontier_cap,
@@ -624,6 +629,20 @@ class Plan:
                 None if self._resident is None else self._resident.source
             ),
         }
+
+    def lower(self, query) -> jax.stages.Lowered:
+        """The lowered driver program that answers a ``SingleSource`` or
+        ``MultiSource`` query (``MultiSource`` is also the server's lane
+        batch), without running it — to inspect what the device will
+        run, e.g. whether a Pallas kernel (``tpu_custom_call``) is in
+        it."""
+        if isinstance(query, SingleSource):
+            run, arg = self._run1, jnp.asarray(query.source, jnp.int32)
+        elif isinstance(query, MultiSource):
+            run, arg = self._run_many, jnp.asarray(query.sources, jnp.int32)
+        else:
+            raise TypeError(f"cannot lower {type(query).__name__!r}")
+        return run.func.lower(self.backend, arg, **run.keywords)
 
     # -- query dispatch ------------------------------------------------------
 

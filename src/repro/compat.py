@@ -1,10 +1,8 @@
-"""Version shims over moved/renamed JAX APIs.
+"""One home for the JAX calls whose spelling has moved between releases.
 
-The framework targets current JAX names (``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.enable_x64``); this container pins
-jax 0.4.x where those live under ``jax.experimental`` or do not exist.
-Every call site goes through this module so the rest of the codebase
-reads as if written against one JAX version.
+Written against the installed JAX (0.9), where ``jax.shard_map``,
+``jax.sharding.AxisType`` and ``jax.enable_x64`` all exist. Call sites
+go through this module, so a future rename is fixed here once.
 """
 from __future__ import annotations
 
@@ -12,40 +10,23 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` (>=0.6) / ``jax.experimental.shard_map`` (0.4).
-    ``check_vma`` maps onto the old ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def enable_x64(enabled: bool = True):
     """Context manager enabling 64-bit types (``pred_mode='packed'``)."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(enabled)
-    from jax.experimental import enable_x64 as _e64
-    if enabled:
-        return _e64()
-    from jax.experimental import disable_x64
-    return disable_x64()
+    return jax.enable_x64(enabled)
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returned a one-dict list in 0.4.x and
-    a flat dict today; normalize to the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``Compiled.cost_analysis()`` as a flat dict (empty when the
+    backend reports nothing)."""
+    return compiled.cost_analysis() or {}

@@ -19,9 +19,10 @@ outer/inner loop driver (``core.delta_stepping``) hosts every strategy:
   driver's *fused light phase* protocol (``supports_fused_light`` /
   ``fused_iter`` / ``fused_next``), so each inner iteration is one
   kernel step plus O(cap·deg) XLA scatters instead of three full-width
-  passes. On hosts where the kernel cannot run compiled (plain CPU
-  without interpret mode) the build drops to the bitwise-identical jnp
-  twin — the strategy is then a loop-structure optimization only.
+  passes. Off TPU the kernel runs only where the config chooses so
+  explicitly: under the Pallas interpreter (``interpret=True``) or as
+  its bitwise-identical jnp twin (``kernel_twin=True``); see
+  ``kernel_path``.
 * ``sharded_edge`` / ``sharded_ell`` — SPMD variants of the first two:
   edges (or ELL row blocks) are partitioned across a 1-D device mesh
   (``graphs.partition``), each sweep runs per-shard under ``shard_map``
@@ -78,6 +79,7 @@ from repro.graphs.structures import (
 from repro.kernels.bucket_scan import bucket_scan
 from repro.kernels.ell_relax import ell_relax
 from repro.kernels.frontier_relax import frontier_relax
+from repro.kernels.frontier_relax.ops import lane_padded_width
 from repro.kernels.grid_relax import grid_relax
 
 _IMAX = jnp.int32(2**31 - 1)
@@ -252,6 +254,9 @@ class RelaxBackend:
 
     supports_vmap = True
     delta: int
+    # how the strategy's Pallas kernels execute: 'xla' (no kernel),
+    # 'compiled', 'interpret' or 'twin' (see ``kernel_path``)
+    kernel_path = "xla"
 
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         raise NotImplementedError
@@ -262,6 +267,53 @@ class RelaxBackend:
 
 def _static():
     return dataclasses.field(metadata=dict(static=True))
+
+
+KERNEL_STRATEGIES = ("pallas", "fused", "sharded_fused")
+
+
+def kernel_path(cfg) -> str:
+    """How a kernel strategy's Pallas kernels execute here, decided once
+    when the backend is built and reported by ``Plan.explain()``:
+
+    * ``'compiled'`` — on a TPU, always: no kernel strategy runs the
+      interpreter or the jnp twin there, and asking for either is an
+      error rather than a silent swap;
+    * ``'interpret'`` — off TPU with ``interpret=True`` (the CPU test
+      configuration: the kernel body runs in the Pallas interpreter);
+    * ``'twin'`` — off TPU with ``kernel_twin=True`` ('fused' /
+      'sharded_fused' only): the bitwise-identical jnp twin of
+      ``kernels/frontier_relax``.
+
+    Off TPU without either choice the build fails: Pallas TPU kernels
+    compile only for a TPU, and nothing picks a substitute for the
+    caller. Non-kernel strategies return ``'xla'`` and ignore both
+    flags."""
+    if cfg.strategy not in KERNEL_STRATEGIES:
+        return "xla"
+    if cfg.kernel_twin and cfg.strategy == "pallas":
+        raise ValueError("strategy='pallas' has no jnp twin; off TPU use "
+                         "interpret=True")
+    if cfg.interpret and cfg.kernel_twin:
+        raise ValueError("interpret=True and kernel_twin=True are two "
+                         "different kernel paths; choose one")
+    if jax.default_backend() == "tpu":
+        if cfg.interpret or cfg.kernel_twin:
+            raise ValueError(
+                f"strategy={cfg.strategy!r} runs its compiled Pallas "
+                "kernels on TPU; interpret=True / kernel_twin=True are "
+                "off-TPU choices")
+        return "compiled"
+    if cfg.interpret:
+        return "interpret"
+    if cfg.kernel_twin:
+        return "twin"
+    raise ValueError(
+        f"strategy={cfg.strategy!r} compiles its Pallas kernels only for "
+        f"a TPU (this backend is {jax.default_backend()!r}); choose "
+        "interpret=True (Pallas interpreter)"
+        + ("" if cfg.strategy == "pallas"
+           else " or kernel_twin=True (bitwise jnp twin)"))
 
 
 class _FrontierCompactMixin:
@@ -277,11 +329,13 @@ class _FrontierCompactMixin:
 
 class _PallasScanMixin:
     """Bucket bookkeeping on the fused ``kernels/bucket_scan`` Pallas
-    kernel. Consumers declare static fields ``delta`` and ``interpret``."""
+    kernel. Consumers declare static fields ``delta`` and
+    ``kernel_path``."""
 
     def scan(self, dist, explored, bucket_i):
         return bucket_scan(dist, explored, bucket_i, delta=self.delta,
-                           backend="pallas", interpret=self.interpret)
+                           backend="pallas",
+                           interpret=self.kernel_path == "interpret")
 
 
 def _ell_blocks(graph: COOGraph, delta: int, max_deg=None):
@@ -366,14 +420,15 @@ class PallasEllBackend(_FrontierCompactMixin, _PallasScanMixin,
     delta: int = _static()
     n: int = _static()
     cap: int = _static()
-    interpret: bool = _static()
+    kernel_path: str = _static()
     canonical: bool = _static()
 
     @classmethod
     def build(cls, graph: COOGraph, cfg, max_deg=None) -> "PallasEllBackend":
+        path = kernel_path(cfg)
         light, heavy = _ell_blocks(graph, cfg.delta, max_deg)
         return cls(light, heavy, cfg.delta, graph.n_nodes,
-                   cfg.frontier_cap or graph.n_nodes, cfg.interpret,
+                   cfg.frontier_cap or graph.n_nodes, path,
                    graph_is_canonical(graph))
 
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
@@ -381,7 +436,7 @@ class PallasEllBackend(_FrontierCompactMixin, _PallasScanMixin,
         ell = self.light if light else self.heavy
         d = dist_of(tent, packed)
         cand = ell_relax(fidx, d, ell.w, backend="pallas",
-                         interpret=self.interpret)          # (cap, D)
+                         interpret=self.kernel_path == "interpret")
         rows_n = ell.nbr[fidx]
         src_ids = jnp.broadcast_to(fidx[:, None], rows_n.shape)
         if packed and self.canonical:
@@ -400,15 +455,16 @@ class PallasEllBackend(_FrontierCompactMixin, _PallasScanMixin,
         return tent, over
 
 
-def _kernel_viable(cfg) -> bool:
-    """Whether the fused ``pallas_call`` can actually execute here:
-    interpret mode anywhere (the CI configuration — CPU executes the
-    kernel *body* through the Pallas interpreter), compiled only on a
-    real TPU. Everywhere else the fused strategies run their jnp twin,
-    which is bitwise identical by construction (kernels/frontier_relax)
-    — the strategy selection never changes answers, only which engine
-    executes the same dataflow."""
-    return bool(cfg.interpret) or jax.default_backend() == "tpu"
+def _lane_pad_block(nbr, w, sentinel: int):
+    """Pad an ELL block's last (width) axis to whole 128-lane tiles with
+    sentinel columns (neighbor ``sentinel``, weight INF) — the width the
+    compiled ``frontier_relax`` row DMAs need. Done once at build so
+    the kernel's wrapper never copies the block per call; the padded
+    columns are invalid slots every consumer already ignores."""
+    d = nbr.shape[-1]
+    cols = [(0, 0)] * (nbr.ndim - 1) + [(0, lane_padded_width(d) - d)]
+    return (jnp.pad(nbr, cols, constant_values=sentinel),
+            jnp.pad(w, cols, constant_values=INF32))
 
 
 @jax.tree_util.register_dataclass
@@ -433,29 +489,36 @@ class FusedBackend(_FrontierCompactMixin, RelaxBackend):
     delta: int = _static()
     n: int = _static()
     cap: int = _static()
-    kernel: bool = _static()
-    interpret: bool = _static()
+    kernel_path: str = _static()
+    width: int = _static()                # light ELL width before lane pad
     canonical: bool = _static()
 
     @property
     def supports_vmap(self):
         # the kernel path has no batching rule; the jnp twin vmaps fine
-        return not self.kernel
+        return self.kernel_path == "twin"
 
     @classmethod
     def build(cls, graph: COOGraph, cfg, max_deg=None) -> "FusedBackend":
+        path = kernel_path(cfg)
         light, heavy = _ell_blocks(graph, cfg.delta, max_deg)
+        width = light.max_deg
+        if path == "compiled":
+            nbr, w = _lane_pad_block(light.nbr, light.w, graph.n_nodes)
+            light = ELLGraph(nbr, w, light.n_nodes, nbr.shape[1])
         return cls(light, heavy, cfg.delta, graph.n_nodes,
-                   cfg.frontier_cap or graph.n_nodes, _kernel_viable(cfg),
-                   cfg.interpret, graph_is_canonical(graph))
+                   cfg.frontier_cap or graph.n_nodes, path, width,
+                   graph_is_canonical(graph))
 
     def _fused_step(self, dist, explored, bucket_i):
         ell = self.light
-        return frontier_relax(
+        fidx, rows_n, rows_w, count, any_, nxt = frontier_relax(
             dist, explored, bucket_i, ell.nbr, ell.w, delta=self.delta,
             cap=self.cap, base=0, sent=self.n,
-            backend="pallas" if self.kernel else "ref",
-            interpret=self.interpret)
+            backend="ref" if self.kernel_path == "twin" else "pallas",
+            interpret=self.kernel_path == "interpret")
+        return (fidx, rows_n[:, :self.width], rows_w[:, :self.width], count,
+                any_, nxt)
 
     def fused_iter(self, tent, explored, in_s, bucket_i, *, packed: bool):
         """One whole light inner iteration: kernel step (scan + compact
@@ -480,7 +543,7 @@ class FusedBackend(_FrontierCompactMixin, RelaxBackend):
         """Next-bucket min for the driver's bucket advance — the
         kernel's scalar output (the fused replacement of the post-heavy
         ``scan_bucket`` call; bitwise equal, same formulas)."""
-        if self.kernel:
+        if self.kernel_path != "twin":
             return self._fused_step(dist, explored, bucket_i)[5]
         return scan_bucket(dist, explored, bucket_i, delta=self.delta)[2]
 
@@ -509,24 +572,26 @@ class GridPallasBackend(_PallasScanMixin, RelaxBackend):
     delta: int = _static()
     shape: Tuple[int, int] = _static()
     costs: Tuple[int, int] = _static()    # (straight, diagonal)
-    interpret: bool = _static()
+    kernel_path: str = _static()
 
     @classmethod
     def build(cls, graph: COOGraph, cfg, free_mask) -> "GridPallasBackend":
+        path = kernel_path(cfg)
         free = jnp.asarray(free_mask, bool)
         if free.ndim != 2 or free.size != graph.n_nodes:
             raise ValueError(
                 f"free_mask shape {free.shape} does not cover the "
                 f"{graph.n_nodes}-vertex graph")
         return cls(free, cfg.delta, tuple(free.shape),
-                   tuple(cfg.grid_costs), cfg.interpret)
+                   tuple(cfg.grid_costs), path)
 
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         h, w = self.shape
         out = grid_relax(tent.reshape(h, w), self.free, bucket_i,
                          delta=self.delta, cost_straight=self.costs[0],
                          cost_diag=self.costs[1], light=light,
-                         backend="pallas", interpret=self.interpret)
+                         backend="pallas",
+                         interpret=self.kernel_path == "interpret")
         return out.reshape(-1), jnp.zeros((), bool)
 
 
@@ -552,6 +617,20 @@ def resolve_n_shards(n_shards) -> int:
     return int(n_shards)
 
 
+def _shard_mesh(n_shards: int):
+    return compat.make_mesh((n_shards,), (_SHARD_AXIS,))
+
+
+def _place_shards(blocks, n_shards: int):
+    """Put stacked per-shard blocks (leading axis = shard; a pytree of
+    them is fine) one block per mesh device at build time. Left
+    uncommitted they would sit on device 0 and be re-sent to every
+    shard on each call."""
+    spec = jax.sharding.NamedSharding(_shard_mesh(n_shards),
+                                      PartitionSpec(_SHARD_AXIS))
+    return jax.device_put(blocks, spec)
+
+
 def _inf_word(packed: bool):
     return jnp.asarray(packing.INF_PACKED, jnp.int64) if packed \
         else jnp.asarray(INF32, jnp.int32)
@@ -563,7 +642,7 @@ class _ShardedMixin:
     Consumers declare the static field ``n_shards``."""
 
     def _mesh(self):
-        return compat.make_mesh((self.n_shards,), (_SHARD_AXIS,))
+        return _shard_mesh(self.n_shards)
 
     def _shard_map(self, body, n_sharded_args, n_outs):
         spec = PartitionSpec(_SHARD_AXIS)
@@ -599,8 +678,9 @@ class ShardedEdgeBackend(_ShardedMixin, RelaxBackend):
     def build(cls, graph: COOGraph, cfg) -> "ShardedEdgeBackend":
         shards = resolve_n_shards(cfg.n_shards)
         part = partition_edges(graph, shards)
-        return cls(part.src, part.dst, part.w, cfg.delta, graph.n_nodes,
-                   shards, graph_is_canonical(graph))
+        src, dst, w = _place_shards((part.src, part.dst, part.w), shards)
+        return cls(src, dst, w, cfg.delta, graph.n_nodes, shards,
+                   graph_is_canonical(graph))
 
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         delta, n, canonical = self.delta, self.n, self.canonical
@@ -640,7 +720,7 @@ class ShardedEllBackend(_ShardedMixin, RelaxBackend):
     @classmethod
     def build(cls, graph: COOGraph, cfg) -> "ShardedEllBackend":
         shards = resolve_n_shards(cfg.n_shards)
-        part = partition_ell(graph, shards, cfg.delta)
+        part = _place_shards(partition_ell(graph, shards, cfg.delta), shards)
         cap = min(cfg.frontier_cap or part.shard_nodes, part.shard_nodes)
         return cls(part, cfg.delta, graph.n_nodes, shards, cap,
                    graph_is_canonical(graph))
@@ -695,27 +775,34 @@ class ShardedFusedBackend(ShardedEllBackend):
 
     supports_fused_light = True
 
-    kernel: bool = _static()
-    interpret: bool = _static()
+    kernel_path: str = _static()
+    width: int = _static()                # light ELL width before lane pad
 
     @property
     def supports_vmap(self):
-        return not self.kernel
+        return self.kernel_path == "twin"
 
     @classmethod
     def build(cls, graph: COOGraph, cfg) -> "ShardedFusedBackend":
+        path = kernel_path(cfg)
         shards = resolve_n_shards(cfg.n_shards)
         part = partition_ell(graph, shards, cfg.delta)
+        width = part.light_deg
+        if path == "compiled":
+            nbr, w = _lane_pad_block(part.light_nbr, part.light_w,
+                                     graph.n_nodes)
+            part = dataclasses.replace(part, light_nbr=nbr, light_w=w,
+                                       light_deg=nbr.shape[-1])
+        part = _place_shards(part, shards)
         cap = min(cfg.frontier_cap or part.shard_nodes, part.shard_nodes)
         return cls(part, cfg.delta, graph.n_nodes, shards, cap,
-                   graph_is_canonical(graph), _kernel_viable(cfg),
-                   cfg.interpret)
+                   graph_is_canonical(graph), path, width)
 
     def fused_iter(self, tent, explored, in_s, bucket_i, *, packed: bool):
         part = self.part
         n, s_nodes, cap = self.n, part.shard_nodes, self.cap
-        delta, canonical = self.delta, self.canonical
-        kernel, interpret = self.kernel, self.interpret
+        delta, canonical, width = self.delta, self.canonical, self.width
+        path = self.kernel_path
         n_pad = self.n_shards * s_nodes
 
         def body(tent_r, explored_r, in_s_r, i_r, nbr_s, w_s):
@@ -728,8 +815,10 @@ class ShardedFusedBackend(ShardedEllBackend):
             e_loc = lax.dynamic_slice_in_dim(ep, base, s_nodes)
             fidx, rows_n, rows_w, count, any_l, _ = frontier_relax(
                 d_loc, e_loc, i_r, nbr_s, w_s, delta=delta, cap=cap,
-                base=base, sent=n, backend="pallas" if kernel else "ref",
-                interpret=interpret)
+                base=base, sent=n,
+                backend="ref" if path == "twin" else "pallas",
+                interpret=path == "interpret")
+            rows_n, rows_w = rows_n[:, :width], rows_w[:, :width]
             d_f = jnp.take(d, fidx, mode="fill", fill_value=INF32)
             explored_out = lax.pmin(
                 explored_r.at[fidx].set(d_f, mode="drop"), _SHARD_AXIS)

@@ -86,10 +86,14 @@ class DeltaConfig:
                    flag reports violations). For 'sharded_ell' /
                    'sharded_fused' the cap is *per shard* (defaults to
                    the owned vertex range, which cannot overflow).
-    interpret    — 'pallas'/'fused' only: run kernels in interpret mode
-                   (CPU; for 'fused' this is also what opts the build
-                   into the kernel path off-TPU — see backends
-                   ``_kernel_viable``).
+    interpret    — kernel strategies ('pallas'/'fused'/'sharded_fused')
+                   off TPU: run the Pallas kernels in the interpreter.
+                   Refused on TPU, where kernels always run compiled
+                   (backends ``kernel_path``).
+    kernel_twin  — 'fused'/'sharded_fused' off TPU: run the bitwise jnp
+                   twin of the ``frontier_relax`` kernel instead. Off
+                   TPU a kernel strategy needs one of the two; refused
+                   on TPU.
     grid_costs   — 'pallas' on game maps: (straight, diagonal) move
                    costs of the occupancy-grid stencil (paper §4).
     n_shards     — 'sharded_*' only: width of the 1-D device mesh the
@@ -122,6 +126,7 @@ class DeltaConfig:
     pred_mode: str = "argmin"
     frontier_cap: Optional[int] = None
     interpret: bool = False
+    kernel_twin: bool = False
     grid_costs: Tuple[int, int] = (10, 14)
     n_shards: Optional[int] = None
     p2p_mode: str = "early_exit"

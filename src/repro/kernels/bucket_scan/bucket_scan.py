@@ -14,10 +14,12 @@ fuses all three into one pass over ``tent``/``explored``:
                     lets warm re-solves (repro.dynamic) skip buckets the
                     repair never touched (DESIGN.md §11)
 
-Grid is 1-D over row blocks of the (padded) column-major tent layout;
-the two scalar outputs accumulate across sequential grid steps into a
-(1, 1) block (TPU grid execution is sequential, making the accumulation
-race-free — the same argument the paper makes for benign writes).
+Grid is 1-D over row blocks of the (padded) lane layout of tent. The
+bucket index comes in and the two scalar results (``any``, ``next``) go
+out through SMEM: the TPU compiler stores scalars only to SMEM, never
+to VMEM. The scalars accumulate across the sequential grid steps
+(``"arbitrary"`` semantics), which keeps the accumulation race-free —
+the same argument the paper makes for benign writes.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.graphs.structures import INF32
 
@@ -33,10 +36,10 @@ _INF = int(INF32)  # python int: pallas kernels cannot capture traced constants
 _IMAX = 2**31 - 1
 
 
-def bucket_scan_kernel(i_ref, tent_ref, explored_ref, frontier_ref,
-                       any_ref, next_ref, *, delta: int):
+def bucket_scan_kernel(i_ref, tent_ref, explored_ref, frontier_ref, out_ref,
+                       *, delta: int):
     pid = pl.program_id(0)
-    i = i_ref[0, 0]
+    i = i_ref[0]
     t = tent_ref[...]
     e = explored_ref[...]
     fin = t < _INF
@@ -47,35 +50,36 @@ def bucket_scan_kernel(i_ref, tent_ref, explored_ref, frontier_ref,
     @pl.when(pid == 0)
     def _init():
         # explicit int32: under x64 a weak python int would store as int64
-        any_ref[0, 0] = jnp.int32(0)
-        next_ref[0, 0] = jnp.int32(_IMAX)
+        out_ref[0] = jnp.int32(0)
+        out_ref[1] = jnp.int32(_IMAX)
 
-    any_ref[0, 0] = jnp.maximum(any_ref[0, 0], f.any().astype(jnp.int32))
-    nb = jnp.where((b > i) & (t < e), b, _IMAX).min().astype(jnp.int32)
-    next_ref[0, 0] = jnp.minimum(next_ref[0, 0], nb)
+    out_ref[0] = jnp.maximum(out_ref[0], jnp.max(f.astype(jnp.int32)))
+    nb = jnp.min(jnp.where((b > i) & (t < e), b, _IMAX)).astype(jnp.int32)
+    out_ref[1] = jnp.minimum(out_ref[1], nb)
 
 
 def bucket_scan_pallas(tent2d, explored2d, bucket_i, *, delta: int,
                        block_rows: int, interpret: bool = False):
     """tent2d/explored2d: int32[R, 128] padded row-major reshape of tent
-    (padding = INF). Returns (frontier int8[R,128], any int32[1,1],
-    next_bucket int32[1,1])."""
+    (padding = INF). Returns (frontier int8[R,128], scalars int32[2] =
+    (any, next_bucket))."""
     r, lanes = tent2d.shape
     assert r % block_rows == 0
     n_blocks = r // block_rows
-    i_arr = jnp.full((1, 1), bucket_i, jnp.int32)
+    i_arr = jnp.reshape(jnp.asarray(bucket_i, jnp.int32), (1,))
     kernel = functools.partial(bucket_scan_kernel, delta=delta)
-    blk = lambda: pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))
-    scalar = lambda: pl.BlockSpec((1, 1), lambda i: (0, 0))
+    blk = pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
         grid=(n_blocks,),
-        in_specs=[scalar(), blk(), blk()],
-        out_specs=[blk(), scalar(), scalar()],
+        in_specs=[smem, blk, blk],
+        out_specs=[blk, smem],
         out_shape=[
             jax.ShapeDtypeStruct((r, lanes), jnp.int8),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(i_arr, tent2d, explored2d)

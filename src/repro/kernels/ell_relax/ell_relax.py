@@ -3,12 +3,12 @@
 The frontier-centric Δ-stepping sweep gathers the ELL adjacency rows of
 the compacted frontier and produces relaxation candidates
 ``tent[v] + w(v, u)`` (paper's request-set computation, with the C4
-deviation of evaluating costs during generation). The gather is
-irregular; on TPU the idiomatic form is **scalar-prefetch indexing**:
-the compacted frontier indices are prefetched to SMEM and drive the
-BlockSpec index maps, so each grid step DMAs exactly the ELL row block
-and tent row it needs from HBM into VMEM — no host-side gather
-materialization.
+deviation of evaluating costs during generation). The gather itself is
+an XLA gather in ``ops.py``; this kernel fuses the validity mask and
+the add over blocks of gathered rows. (A one-row-per-step variant that
+let the scalar-prefetched index drive each row's DMA is not possible:
+a (1, D) block does not divide the (8, 128) tiling the TPU compiler
+requires of VMEM blocks.)
 
 Grid: one step per block of ``rows_per_block`` frontier entries; padding
 entries point at the all-sentinel row ``n`` and yield INF candidates.
@@ -53,9 +53,9 @@ def ell_relax_pallas(fidx, dist_col, w_ell, *, rows_per_block: int,
         del fidx_ref
         return (i, 0)
 
-    # Scalar-prefetch: block index maps read the frontier indices. With
-    # rows_per_block == 1 each step DMAs exactly row fidx[i]; for larger
-    # blocks we fall back to a gathered layout prepared by ops.py.
+    # The rows arrive already gathered by ops.py (an XLA gather): a
+    # one-row block per frontier entry would not divide the (8, 128)
+    # tiling the TPU compiler requires of VMEM blocks.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks,),
@@ -72,29 +72,3 @@ def ell_relax_pallas(fidx, dist_col, w_ell, *, rows_per_block: int,
         interpret=interpret,
     )(fidx, dist_col, w_ell)
 
-
-def ell_relax_row_gather_pallas(fidx, dist, w_ell, *, interpret: bool = False):
-    """Fully-fused variant (rows_per_block=1): the scalar-prefetched
-    frontier index drives the DMA of each ELL row directly, so the
-    gather itself happens inside the kernel pipeline."""
-    cap = fidx.shape[0]
-    d = w_ell.shape[1]
-
-    def row_map(i, fidx_ref):
-        return (fidx_ref[i], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(cap,),
-        in_specs=[
-            pl.BlockSpec((1, 1), row_map),
-            pl.BlockSpec((1, d), row_map),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda i, _: (i, 0)),
-    )
-    return pl.pallas_call(
-        ell_relax_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((cap, d), jnp.int32),
-        interpret=interpret,
-    )(fidx, dist, w_ell)

@@ -6,21 +6,19 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.graphs.structures import INF32
-from repro.kernels.ell_relax.ell_relax import (
-    ell_relax_pallas,
-    ell_relax_row_gather_pallas,
-)
+from repro.kernels.ell_relax.ell_relax import ell_relax_pallas
 from repro.kernels.ell_relax.ref import ell_relax_ref
 
 
 def ell_relax(fidx, dist, w_ell, *, backend: str = "pallas",
-              rows_per_block: int = 8, interpret: bool = False):
+              rows_per_block: int = 256, interpret: bool = False):
     """Relaxation candidates for a compacted frontier.
 
     fidx: int32[cap] frontier vertex ids (n = padding sentinel).
     dist: int32[n] tentative distances.
     w_ell: int32[n+1, D] ELL weights (row n all-INF).
     Returns int32[cap, D] candidate distances (INF where invalid).
+    ``rows_per_block`` (a multiple of 8) is clamped to the padded cap.
     """
     if backend == "ref":
         return ell_relax_ref(fidx, dist, w_ell)
@@ -28,10 +26,8 @@ def ell_relax(fidx, dist, w_ell, *, backend: str = "pallas",
     cap = fidx.shape[0]
     dist_col = jnp.concatenate(
         [dist, jnp.full((1,), INF32, dist.dtype)])[:, None]   # (n+1, 1)
-    if backend == "pallas_row":
-        return ell_relax_row_gather_pallas(fidx, dist_col, w_ell,
-                                           interpret=interpret)
-    # blocked variant: gather rows first (XLA), kernel fuses mask+add
+    # gather rows first (XLA), kernel fuses mask+add
+    rows_per_block = min(rows_per_block, -(-cap // 8) * 8)
     pad = (-cap) % rows_per_block
     fidx_p = jnp.concatenate(
         [fidx, jnp.full((pad,), n, fidx.dtype)]) if pad else fidx

@@ -1,11 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", ""))
-# The two lines above MUST run before any other import: jax locks the
-# device count at first initialization. 512 placeholder host devices back
-# the 16x16 single-pod and 2x16x16 multi-pod production meshes.
-
 """Multi-pod dry-run driver (deliverable e).
 
 For every (architecture × input-shape) cell, on the single-pod 16×16
@@ -26,12 +18,21 @@ Usage:
   python -m repro.launch.dryrun --arch yi-34b --shape train_4k --mesh multi
   python -m repro.launch.dryrun --family lm --mesh single
   python -m repro.launch.dryrun --out reports.json --append
+
+``main()`` backs the production meshes with 512 placeholder host
+devices: it sets ``XLA_FLAGS`` before JAX initializes its backends, so
+it must run in a fresh process (importing this module sets nothing).
+The roofline terms are against the v5e target's peaks
+(``TARGET_DEVICE_KIND``), not the host that compiles.
 """
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
+
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, hbm_limit_gb=16.0):
@@ -50,7 +51,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, hbm_limit_gb=16.0):
     report = analyze(
         compiled, arch=arch, shape=shape_name, mesh_name=mesh_name(mesh),
         n_devices=mesh.size, model_flops=cell.model_flops,
-        note=cell.note)
+        device_kind=TARGET_DEVICE_KIND, note=cell.note)
     d = report.to_json()
     d["lower_s"] = round(t_lower, 1)
     d["compile_s"] = round(t_compile, 1)
@@ -65,6 +66,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, hbm_limit_gb=16.0):
 
 
 def main():
+    # jax locks the device count when its backends first initialize
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", ""))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

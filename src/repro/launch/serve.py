@@ -35,7 +35,7 @@ def _serve_sssp(args):
     auto = args.tune or args.tune_cache is not None
     config = DeltaConfig(delta=args.delta, strategy=args.strategy,
                          n_shards=args.shards, policy=args.policy,
-                         rho=args.rho)
+                         rho=args.rho, interpret=args.interpret)
     if not auto and args.policy != "delta":
         print(f"[serve] frontier policy: {args.policy}")
     if not auto and args.strategy.startswith("sharded"):
@@ -131,6 +131,9 @@ def main():
     ap.add_argument("--shards", type=int, default=None,
                     help="SSSP mode, sharded_* strategies: mesh width "
                          "(default: every local device)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="SSSP mode, fused strategies off TPU: run the "
+                         "kernel in the Pallas interpreter")
     ap.add_argument("--policy", default="delta",
                     choices=["delta", "rho", "radius"],
                     help="SSSP mode: frontier-selection policy "
@@ -155,4 +158,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main()

@@ -8,8 +8,8 @@ Uses the single-device engine by default; ``--sources K`` with
 program (``DeltaSteppingSolver.solve_many``). ``--devices N`` (with
 XLA_FLAGS=--xla_force_host_platform_device_count=N) runs the
 distributed shard_map engine on an (sources × N_model) mesh.
-``--strategy pallas`` routes relaxation through the Pallas kernels
-(add ``--interpret`` off-TPU); on ``--graph gamemap`` that selects the
+``--strategy pallas`` / ``fused`` route relaxation through the Pallas
+kernels (compiled on TPU; off TPU they need ``--interpret``); on ``--graph gamemap`` that selects the
 grid-stencil kernel. ``--strategy sharded_edge`` / ``sharded_ell``
 selects the mesh-sharded first-class backends (DESIGN.md §9) —
 relaxation partitioned over ``--shards`` devices (default: all) inside
@@ -67,7 +67,8 @@ def main():
                     help="--policy radius: r(v) = k-th smallest "
                          "outgoing edge weight")
     ap.add_argument("--interpret", action="store_true",
-                    help="run pallas kernels in interpret mode (CPU)")
+                    help="run the Pallas kernels in the interpreter "
+                         "(off TPU only)")
     ap.add_argument("--sources", type=int, default=1)
     ap.add_argument("--target", type=int, default=None,
                     help="point-to-point query: early-exit solve from "
@@ -228,4 +229,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main()

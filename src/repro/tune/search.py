@@ -157,19 +157,21 @@ def default_strategies() -> Tuple[str, ...]:
     """The tuner's default strategy axis: the single-device backends
     always, plus the mesh-sharded backends whenever the process actually
     has a mesh to shard over (>1 local device) — the mesh shape itself
-    is pinned by the fingerprint's ``dev=`` term (DESIGN.md §9)."""
+    is pinned by the fingerprint's ``dev=`` term (DESIGN.md §9).
+
+    The fused kernel strategies join only on a TPU, the one place their
+    Pallas kernel compiles: off TPU they would measure the interpreter
+    or the jnp twin, which says nothing about the kernel, so they are
+    left out here rather than substituted (core.backends
+    ``kernel_path``)."""
     import jax
 
-    if jax.device_count() > 1:
-        return (
-            "edge",
-            "ell",
-            "fused",
-            "sharded_edge",
-            "sharded_ell",
-            "sharded_fused",
-        )
-    return ("edge", "ell", "fused")
+    single = ("edge", "ell")
+    sharded = ("sharded_edge", "sharded_ell")
+    if jax.default_backend() == "tpu":
+        single += ("fused",)
+        sharded += ("sharded_fused",)
+    return single + sharded if jax.device_count() > 1 else single
 
 
 def default_policies() -> Tuple[str, ...]:
@@ -236,16 +238,14 @@ def _solver_no_warn(graph, cfg, free_mask=None):
 
 def _candidate_solver(graph, cfg, sources, free_mask=None):
     """Build + warm up + validate one candidate's solver; ``None`` when
-    the config is unusable for *any* probe source (overflow or build
-    failure) — an overflowed run is a wrong-answer run and its time
-    must never compete."""
-    try:
-        solver = _solver_no_warn(graph, cfg, free_mask=free_mask)
-        for s in sources:  # warm up / compile + validate every source
-            if bool(solver.solve(int(s)).overflow):
-                return None
-    except Exception:
-        return None
+    the config overflows its frontier cap for *any* probe source — an
+    overflowed run is a wrong-answer run and its time must never
+    compete. Build and compile errors propagate: a candidate the
+    compiler refuses is a fault to see, not a slow candidate."""
+    solver = _solver_no_warn(graph, cfg, free_mask=free_mask)
+    for s in sources:  # warm up / compile + validate every source
+        if bool(solver.solve(int(s)).overflow):
+            return None
     return solver
 
 
@@ -350,7 +350,7 @@ def tune(
                 solvers[key] = _candidate_solver(
                     graph, cfg, sources, free_mask=free_mask
                 )
-            if solvers[key] is None:
+            if solvers[key] is None:  # overflowed: never competes
                 return float("inf")
             return _time_solver(solvers[key], sources, reps)
 
@@ -368,7 +368,7 @@ def tune(
         timed = [t for t in timed if np.isfinite(t[0])]
         evidence.update({cand: t for t, cand in timed})
         if not timed:
-            # every candidate overflowed/failed: fall back to heuristic
+            # every candidate overflowed: fall back to heuristic
             return heuristic_record(graph, base, stats)
         if len(timed) == 1:
             break

@@ -8,6 +8,7 @@ two files share this one implementation so a fix to the fallback
 seeding or the ``@given`` wrapping cannot silently diverge between
 them.
 """
+import jax
 import numpy as np
 
 # Every RelaxBackend strategy the engine exposes, in registration order —
@@ -67,3 +68,25 @@ class null_ctx:
 
     def __exit__(self, *a):
         return False
+
+
+# XLA:CPU maps memory for every executable it compiles. The cross-product
+# suites compile thousands of program variants (each adversarial seed
+# brings its own ELL widths), and a process past the kernel's
+# memory-map budget (vm.max_map_count, 65530 by default) segfaults
+# inside its next compile.
+MAX_MAPPINGS = 55_000
+
+
+def release_compiled_programs(limit: int = MAX_MAPPINGS) -> None:
+    """Drop JAX's caches — and with them the compiled executables — once
+    this process holds more than ``limit`` memory mappings. Programs
+    recompile on their next use; answers are unaffected. A no-op where
+    ``/proc/self/maps`` does not exist."""
+    try:
+        with open("/proc/self/maps") as f:
+            n = f.read().count("\n")
+    except OSError:
+        return
+    if n > limit:
+        jax.clear_caches()

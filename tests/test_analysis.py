@@ -2,9 +2,10 @@
 roofline term arithmetic on a real compiled program."""
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.analysis import collective_stats, estimate_model_flops
-from repro.analysis.roofline import V5E, analyze
+from repro.analysis.roofline import PEAKS, analyze
 from repro.configs import get_config, get_shape
 
 _FAKE_HLO = """
@@ -43,11 +44,24 @@ def test_roofline_on_real_compiled_program():
     a = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     compiled = f.lower(a, a).compile()
     rep = analyze(compiled, arch="toy", shape="matmul", mesh_name="1",
-                  n_devices=1, model_flops=2 * 256**3)
+                  n_devices=1, model_flops=2 * 256**3,
+                  device_kind="TPU v5 lite")
     assert rep.flops_per_dev > 0
-    assert rep.compute_s == rep.flops_per_dev / V5E["peak_flops"]
+    assert rep.compute_s == (rep.flops_per_dev
+                             / PEAKS["TPU v5 lite"]["peak_flops"])
     assert rep.dominant in ("compute", "memory", "collective")
     assert 0 < rep.useful_ratio <= 1.5
+
+
+def test_roofline_unknown_device_kind_raises():
+    """Peaks come only from the published table: a device that is not
+    in it (here the host CPU) is an error, not a v5e stand-in."""
+    compiled = jax.jit(lambda a: a + 1).lower(
+        jax.ShapeDtypeStruct((8,), jnp.float32)).compile()
+    with pytest.raises(ValueError, match="no published peaks"):
+        analyze(compiled, arch="toy", shape="add", mesh_name="1",
+                n_devices=1, model_flops=8,
+                device_kind=jax.devices()[0].device_kind)
 
 
 def test_model_flops_estimates_sane():

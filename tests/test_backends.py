@@ -57,13 +57,14 @@ def test_backend_routing():
         make_backend(g, DeltaConfig(strategy="edge")), EdgeBackend)
     assert isinstance(
         make_backend(g, DeltaConfig(strategy="ell")), EllBackend)
-    assert isinstance(
-        make_backend(g, DeltaConfig(strategy="pallas")), PallasEllBackend)
-    assert isinstance(
-        make_backend(g, DeltaConfig(strategy="pallas"), free_mask=free),
-        GridPallasBackend)
+    # off TPU a kernel strategy runs only on an explicit interpret choice
+    kern = DeltaConfig(strategy="pallas", interpret=True)
+    assert isinstance(make_backend(g, kern), PallasEllBackend)
+    assert isinstance(make_backend(g, kern, free_mask=free),
+                      GridPallasBackend)
     with pytest.raises(ValueError):
-        make_backend(g, DeltaConfig(strategy="pallas", pred_mode="packed"),
+        make_backend(g, DeltaConfig(strategy="pallas", pred_mode="packed",
+                                    interpret=True),
                      free_mask=free)
 
 
@@ -99,3 +100,56 @@ def test_pallas_ell_backend_with_frontier_cap():
         g, DeltaConfig(delta=100, strategy="pallas", interpret=True,
                        frontier_cap=2)).solve(0)
     assert bool(res2.overflow)
+
+# ---------------------------------------------------------------------------
+# kernel path: chosen once at build, never substituted behind the caller
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("strategy", ["pallas", "fused", "sharded_fused"])
+def test_kernel_strategy_off_tpu_needs_an_explicit_path(strategy):
+    """Off TPU a kernel strategy builds only on an explicit choice —
+    the Pallas interpreter, or (fused only) the jnp twin — and
+    ``Plan.explain()`` names the path that runs."""
+    from repro.api import Engine
+
+    g, _ = GRAPHS["smallworld"]
+    with pytest.raises(ValueError, match="only for a TPU"):
+        make_backend(g, DeltaConfig(strategy=strategy))
+    plan = Engine(g, DeltaConfig(strategy=strategy, interpret=True)).plan()
+    assert plan.explain()["kernel_path"] == "interpret"
+    if strategy == "pallas":
+        with pytest.raises(ValueError, match="no jnp twin"):
+            make_backend(g, DeltaConfig(strategy=strategy, kernel_twin=True))
+    else:
+        plan = Engine(g, DeltaConfig(strategy=strategy,
+                                     kernel_twin=True)).plan()
+        assert plan.explain()["kernel_path"] == "twin"
+
+
+def test_kernel_path_on_tpu_is_always_compiled(monkeypatch):
+    """On a TPU every kernel strategy runs compiled: asking for the
+    interpreter or the twin there is an error, not a silent swap."""
+    import jax
+
+    from repro.core.backends import kernel_path
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for strategy in ("pallas", "fused", "sharded_fused"):
+        assert kernel_path(DeltaConfig(strategy=strategy)) == "compiled"
+        with pytest.raises(ValueError, match="off-TPU choices"):
+            kernel_path(DeltaConfig(strategy=strategy, interpret=True))
+    with pytest.raises(ValueError, match="off-TPU choices"):
+        kernel_path(DeltaConfig(strategy="fused", kernel_twin=True))
+    assert kernel_path(DeltaConfig(strategy="edge")) == "xla"
+
+
+def test_non_kernel_strategies_report_xla():
+    from repro.api import Engine
+
+    g, _ = GRAPHS["rmat"]
+    for strategy in ("edge", "ell", "sharded_edge", "sharded_ell"):
+        plan = Engine(g, DeltaConfig(strategy=strategy)).plan()
+        assert plan.explain()["kernel_path"] == "xla", strategy
+    with pytest.raises(ValueError, match="choose one"):
+        make_backend(g, DeltaConfig(strategy="fused", kernel_twin=True,
+                                    interpret=True))

@@ -21,12 +21,12 @@ predecessor cycle; test_determinism.py documents argmin's divergences).
 """
 from functools import partial
 
-import jax
 import numpy as np
 import pytest
 
 from _property_driver import (
-    ALL_POLICIES, ALL_STRATEGIES, drive, null_ctx as _null)
+    ALL_POLICIES, ALL_STRATEGIES, drive, null_ctx as _null,
+    release_compiled_programs)
 from repro.compat import enable_x64
 from repro.core import (
     DeltaConfig,
@@ -41,18 +41,6 @@ drive_seed = partial(
     drive,
     strategy=lambda st: st.integers(min_value=0, max_value=2**31 - 1),
     fallback_draw=lambda rng: int(rng.integers(0, 2**31)))
-
-
-# This module compiles the single largest program population in the
-# suite (backend × pred × Δ × policy); on top of the hundreds of
-# executables cached by the modules that run before it, XLA's CPU
-# compiler can segfault mid-compile (single-core boxes, deterministic).
-# An empty cache is the state this module is validated under standalone,
-# and costs nothing here: every program below is keyed on this module's
-# own fixed shape and compiles fresh either way.
-@pytest.fixture(scope="module", autouse=True)
-def _fresh_compile_caches():
-    jax.clear_caches()
 
 
 # One fixed shape for every case: the shape is the jit cache key, the
@@ -85,6 +73,7 @@ def adversarial_coo(seed: int):
 
 
 def _solve(g, source, strategy, pred_mode, delta, policy="delta"):
+    release_compiled_programs()
     # rho pinned small so the ρ-batches actually split the frontier on
     # N=32 graphs (the interesting regime; rho >= N degenerates to one
     # Dijkstra-like giant step only when every vertex is pending)

@@ -25,7 +25,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import numpy as np
 import pytest
 
@@ -35,18 +34,6 @@ from repro.core import DeltaConfig, DeltaSteppingSolver, dijkstra
 from repro.graphs import watts_strogatz
 from repro.graphs.structures import COOGraph, INF32
 from repro.kernels.frontier_relax import frontier_relax, frontier_relax_ref
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _fresh_executable_caches():
-    # In a full tier-1 run ~200 tests' worth of live compiled executables
-    # precede this module, and XLA:CPU's JIT segfaults compiling the
-    # oracle's first (tiny) reduction under that accumulation — each half
-    # of the preceding suite passes alone, only the full prefix crashes.
-    # Dropping the accumulated executables before this module compiles
-    # keeps the process under the limit; later modules just recompile.
-    jax.clear_caches()
-    yield
 
 
 def _solve(g, src, strategy, **kw):
@@ -136,7 +123,8 @@ def _star(k: int) -> COOGraph:
     return COOGraph(src=src, dst=dst, w=w, n_nodes=k + 1)
 
 
-@pytest.mark.parametrize("kernel_kw", [dict(interpret=True), dict()],
+@pytest.mark.parametrize("kernel_kw", [dict(interpret=True),
+                                       dict(kernel_twin=True)],
                          ids=["kernel-interpret", "ref-twin"])
 def test_fused_bitwise_equals_edge(kernel_kw):
     """Both fused execution paths (interpret-mode kernel; CPU oracle
@@ -158,7 +146,7 @@ def test_exact_fill_frontier_no_overflow():
     k = 13
     g = _star(k)
     base = _solve(g, 0, "edge", delta=2, pred_mode="argmin")
-    for kw in (dict(interpret=True), dict()):
+    for kw in (dict(interpret=True), dict(kernel_twin=True)):
         res = _solve(g, 0, "fused", delta=2, frontier_cap=k,
                      pred_mode="argmin", **kw)
         _assert_bitwise(res, base, kw)
@@ -260,7 +248,7 @@ _ACCEPTANCE = textwrap.dedent("""
                 g, DeltaConfig(delta=delta, strategy="fused",
                                pred_mode="packed", interpret=True)
             ).solve(src)
-            for kw in (dict(interpret=True), dict()):
+            for kw in (dict(interpret=True), dict(kernel_twin=True)):
                 cfg = DeltaConfig(delta=delta, strategy="sharded_fused",
                                   pred_mode="packed", n_shards=8, **kw)
                 r = DeltaSteppingSolver(g, cfg).solve(src)

@@ -24,6 +24,7 @@ from repro.tune import (
     TuningCache,
     TuningRecord,
     candidate_configs,
+    default_strategies,
     estimate_delta,
     fingerprint,
     graph_stats,
@@ -375,7 +376,7 @@ def test_measured_tuned_solve_bitwise_equals_explicit():
     tuned = DeltaSteppingSolver(g, cfg).solve(0)
     explicit = delta_stepping(
         g, 0, DeltaConfig(delta=rec.delta, strategy=rec.strategy,
-                          frontier_cap=rec.frontier_cap))
+                          frontier_cap=rec.frontier_cap, policy=rec.policy))
     for a, b in zip(tuned, explicit):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     dref, _ = dijkstra(g, 0)
@@ -455,3 +456,23 @@ def test_server_tunes_at_graph_load(tmp_path):
     (done,) = srv.run_to_completion()
     dref, _ = dijkstra(g, 0)
     np.testing.assert_array_equal(done.dist, dref)
+
+
+def test_default_strategies_leave_kernels_out_off_tpu():
+    """Off TPU the fused kernel strategies are not measured at all: the
+    interpreter or the jnp twin would stand in for the kernel."""
+    import jax
+
+    strategies = default_strategies()
+    assert jax.default_backend() != "tpu"
+    assert not {"fused", "sharded_fused", "pallas"} & set(strategies)
+    assert {"edge", "ell"} <= set(strategies)
+
+
+def test_tuner_reraises_candidate_build_errors():
+    """A candidate that cannot be built is a fault to see, not a slow
+    candidate to drop: here ``fused`` without a kernel path off TPU."""
+    g = watts_strogatz(120, 6, 0.05, seed=0)
+    with pytest.raises(ValueError, match="only for a TPU"):
+        tune(g, deltas=(5,), strategies=("edge", "fused"),
+             policies=("delta",))
