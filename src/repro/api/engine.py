@@ -50,6 +50,7 @@ from typing import Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.paths import extract_path, stitch_bidirectional_path
 from repro.api.queries import (
@@ -310,7 +311,16 @@ class Plan:
         routes through ``update`` + ``resolve`` and does not participate
         in overflow demotion (the warm contract itself refuses an
         overflowed resident state, and the overflow flag of the re-solve
-        is reported in its telemetry)."""
+        is reported in its telemetry).
+
+        Under ``jax.profiler`` the call is a ``plan.solve`` host span
+        (with the query kind), and a demotion a ``plan.demote`` span
+        inside it: the twin's build and its first solve, which compiles
+        the full-width program."""
+        with TraceAnnotation("plan.solve", kind=type(query).__name__):
+            return self._solve(query)
+
+    def _solve(self, query: Query) -> Result:
         if isinstance(query, UpdateBatch):
             self.update(query.edge_ids, query.new_weights)
             return self.resolve(warm=query.warm)
@@ -318,21 +328,23 @@ class Plan:
             return _mark_fallback(self._demoted._dispatch(query))
         res = self._dispatch(query)
         if self._fallback and bool(np.any(np.asarray(res.telemetry.overflow))):
-            self._demoted = Plan(
-                self.graph,
-                dataclasses.replace(self.config, frontier_cap=None),
-                free_mask=self.free_mask,
-                record=self.record,
-                radii_store=self._radii_store,
-            )
-            # residency survives demotion: the resident answer was
-            # solved on the same graph/pred_mode (only the cap differs),
-            # so update/resolve keep working after an overflow demotes
-            self._demoted._resident = self._resident
-            # landmark residency too: tables/spec only depend on the
-            # graph, never on the frontier cap
-            self._demoted._landmarks = self._landmarks
-            res = _mark_fallback(self._demoted._dispatch(query))
+            with TraceAnnotation("plan.demote"):
+                self._demoted = Plan(
+                    self.graph,
+                    dataclasses.replace(self.config, frontier_cap=None),
+                    free_mask=self.free_mask,
+                    record=self.record,
+                    radii_store=self._radii_store,
+                )
+                # residency survives demotion: the resident answer was
+                # solved on the same graph/pred_mode (only the cap
+                # differs), so update/resolve keep working after an
+                # overflow demotes
+                self._demoted._resident = self._resident
+                # landmark residency too: tables/spec only depend on the
+                # graph, never on the frontier cap
+                self._demoted._landmarks = self._landmarks
+                res = _mark_fallback(self._demoted._dispatch(query))
         return res
 
     # -- dynamic updates (repro.dynamic, DESIGN.md §11) ----------------------

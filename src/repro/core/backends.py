@@ -261,6 +261,7 @@ class RelaxBackend:
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         raise NotImplementedError
 
+    @jax.named_scope("bucket_scan")
     def scan(self, dist, explored, bucket_i):
         return scan_bucket(dist, explored, bucket_i, delta=self.delta)
 
@@ -332,6 +333,7 @@ class _PallasScanMixin:
     kernel. Consumers declare static fields ``delta`` and
     ``kernel_path``."""
 
+    @jax.named_scope("bucket_scan")
     def scan(self, dist, explored, bucket_i):
         return bucket_scan(dist, explored, bucket_i, delta=self.delta,
                            backend="pallas",
@@ -367,6 +369,7 @@ class EdgeBackend(RelaxBackend):
         return cls(graph.src, graph.dst, graph.w, cfg.delta,
                    graph_is_canonical(graph))
 
+    @jax.named_scope("edge_sweep")
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         tent = edge_sweep(tent, mask, self.src, self.dst, self.w,
                           delta=self.delta, light=light, packed=packed,
@@ -395,6 +398,7 @@ class EllBackend(_FrontierCompactMixin, RelaxBackend):
                    cfg.frontier_cap or graph.n_nodes,
                    graph_is_canonical(graph))
 
+    @jax.named_scope("ell_sweep")
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         fidx, over = self.compact(mask)
         ell = self.light if light else self.heavy
@@ -431,6 +435,7 @@ class PallasEllBackend(_FrontierCompactMixin, _PallasScanMixin,
                    cfg.frontier_cap or graph.n_nodes, path,
                    graph_is_canonical(graph))
 
+    @jax.named_scope("ell_relax")
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         fidx, over = self.compact(mask)
         ell = self.light if light else self.heavy
@@ -510,6 +515,7 @@ class FusedBackend(_FrontierCompactMixin, RelaxBackend):
                    cfg.frontier_cap or graph.n_nodes, path, width,
                    graph_is_canonical(graph))
 
+    @jax.named_scope("frontier_relax")
     def _fused_step(self, dist, explored, bucket_i):
         ell = self.light
         fidx, rows_n, rows_w, count, any_, nxt = frontier_relax(
@@ -547,6 +553,7 @@ class FusedBackend(_FrontierCompactMixin, RelaxBackend):
             return self._fused_step(dist, explored, bucket_i)[5]
         return scan_bucket(dist, explored, bucket_i, delta=self.delta)[2]
 
+    @jax.named_scope("ell_sweep")
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         fidx, over = self.compact(mask)
         ell = self.light if light else self.heavy
@@ -585,6 +592,7 @@ class GridPallasBackend(_PallasScanMixin, RelaxBackend):
         return cls(free, cfg.delta, tuple(free.shape),
                    tuple(cfg.grid_costs), path)
 
+    @jax.named_scope("grid_relax")
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         h, w = self.shape
         out = grid_relax(tent.reshape(h, w), self.free, bucket_i,
@@ -682,6 +690,7 @@ class ShardedEdgeBackend(_ShardedMixin, RelaxBackend):
         return cls(src, dst, w, cfg.delta, graph.n_nodes, shards,
                    graph_is_canonical(graph))
 
+    @jax.named_scope("sharded_edge_sweep")
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         delta, n, canonical = self.delta, self.n, self.canonical
 
@@ -725,6 +734,7 @@ class ShardedEllBackend(_ShardedMixin, RelaxBackend):
         return cls(part, cfg.delta, graph.n_nodes, shards, cap,
                    graph_is_canonical(graph))
 
+    @jax.named_scope("sharded_ell_sweep")
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
         part = self.part
         nbr = part.light_nbr if light else part.heavy_nbr
@@ -798,6 +808,7 @@ class ShardedFusedBackend(ShardedEllBackend):
         return cls(part, cfg.delta, graph.n_nodes, shards, cap,
                    graph_is_canonical(graph), path, width)
 
+    @jax.named_scope("frontier_relax")
     def fused_iter(self, tent, explored, in_s, bucket_i, *, packed: bool):
         part = self.part
         n, s_nodes, cap = self.n, part.shard_nodes, self.cap

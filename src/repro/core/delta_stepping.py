@@ -377,6 +377,7 @@ def _run_backend(backend: RelaxBackend, source, *, n: int, packed: bool,
     def scan(tent, explored, i):
         return backend.scan(_dist_of(tent, packed), explored, i)
 
+    @jax.named_scope("light_phase")
     def light_phase(tent, explored, i, inner, over):
         in_s0 = jnp.zeros((n,), bool)
         f0, go0, _ = scan(tent, explored, i)
@@ -412,6 +413,7 @@ def _run_backend(backend: RelaxBackend, source, *, n: int, packed: bool,
     # classic loop (same op sequence on the same states).
     fused = getattr(backend, "supports_fused_light", False)
 
+    @jax.named_scope("light_phase")
     def light_phase_fused(tent, explored, i, inner, over):
         in_s0 = jnp.zeros((n,), bool)
 
@@ -439,7 +441,9 @@ def _run_backend(backend: RelaxBackend, source, *, n: int, packed: bool,
         tent, explored, in_s, inner, over = phase(
             tent, explored, i, inner, over)
         # heavy pass from S (paper Alg. 1 lines 19-20)
-        tent, o = backend.sweep(tent, in_s, i, light=False, packed=packed)
+        with jax.named_scope("heavy_sweep"):
+            tent, o = backend.sweep(tent, in_s, i, light=False,
+                                    packed=packed)
         if fused:
             nxt = backend.fused_next(_dist_of(tent, packed), explored, i)
         else:
@@ -628,6 +632,7 @@ def _run_policy_warm(backend: RelaxBackend, tent0, explored0, *, policy,
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("n",))
+@jax.named_scope("pred_argmin")
 def pred_argmin(dist, src, dst, w, source, *, n: int):
     """Recover a shortest-path tree from converged distances: for every
     edge achieving dist[src] + w == dist[dst], scatter-min the source id.

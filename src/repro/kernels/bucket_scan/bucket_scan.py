@@ -82,4 +82,5 @@ def bucket_scan_pallas(tent2d, explored2d, bucket_i, *, delta: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="bucket_scan",
     )(i_arr, tent2d, explored2d)

@@ -70,5 +70,6 @@ def ell_relax_pallas(fidx, dist_col, w_ell, *, rows_per_block: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((cap, d), jnp.int32),
         interpret=interpret,
+        name="ell_relax",
     )(fidx, dist_col, w_ell)
 

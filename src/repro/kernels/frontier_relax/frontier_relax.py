@@ -180,4 +180,5 @@ def frontier_relax_pallas(dist2d, explored2d, bucket_i, base, nbr, w_ell, *,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="frontier_relax",
     )(scalars, dist2d, explored2d, nbr, w_ell)

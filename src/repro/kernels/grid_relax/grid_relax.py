@@ -104,4 +104,5 @@ def grid_relax_pallas(tent, free_i8, bucket_i, *, delta: int,
         out_specs=strip(lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.int32),
         interpret=interpret,
+        name="grid_relax",
     )(i_arr, tent, tent, tent, free_i8)

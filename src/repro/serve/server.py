@@ -35,11 +35,27 @@ deprecated ``SSSPServer``'s synchronous fixed-cadence stepping.
   accepted request is never dropped: every ticket resolves with a
   result or a typed rejection.
 
-Per-request telemetry (``Ticket.trace``) records the
-enqueue→batch→solve→extract timestamps and batch occupancy;
-``Server.stats()`` aggregates them into p50/p99 latency, shed counts
-and occupancy — the numbers ``benchmarks/bench_serving.py`` sweeps
-into the repo's latency-SLO record.
+Per-request telemetry (``Ticket.trace``, a ``RequestTrace``) records
+the request and batch ids, the submit→batch→solve→ready→done
+timestamps, batch occupancy and the bytes copied to the host for the
+request. ``Server.stats()`` aggregates them into shed counts,
+occupancy, bytes copied and p50/p99 of ``t_done − t_submit``. A lane
+batch's ``t_done`` comes after its rows are ready on the device; a
+solo batch's (``MultiSource``, ``ManyToMany``) when its dispatch
+returned, which for ``MultiSource`` is before its rows are ready.
+
+Under ``jax.profiler`` the serving thread records host spans on the
+device trace's clock (``TraceAnnotation``; no-ops when no trace is
+taken). Every moment of the thread lies in one top-level span:
+``serve.wait_work`` (waiting for a request) or ``serve.batch`` (one
+per batch, with its ``batch_id``, ``kind``, ``lanes``, ``tenant`` and
+``requests``), whose children are, in order, ``serve.form_batch``,
+``serve.plan_build`` (only when a plan is built), ``serve.dispatch``
+(the plan call returning), ``serve.await_device`` (lane batches: until
+``dist``/``pred`` are ready) and ``serve.answer`` (row copies,
+``serve.copy_rows`` and ``serve.extract_path`` per point-to-point lane,
+and ticket resolution; an update batch resolves each ticket inside its
+dispatch). ``serve.submit`` marks each submit on the caller's thread.
 """
 
 from __future__ import annotations
@@ -50,8 +66,10 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api import (
     BoundedRadius,
@@ -111,8 +129,17 @@ class RequestTrace:
     """Per-request serving telemetry: where one request's latency went.
     Timestamps are ``clock()`` values (``time.monotonic`` by default);
     ``t_batch``/``t_solve``/``t_done`` stay ``None`` for requests shed
-    before reaching that stage. ``batch_occupancy`` is real lanes /
-    ``lane_width`` for lane batches, 1.0 for solo and update batches."""
+    before reaching that stage. ``t_solve`` is when a lane batch's
+    dispatch returned (a solo or update request's: when it began);
+    ``t_ready`` when a lane batch's ``dist``/``pred`` were ready on the
+    device, ``None`` for solo and update batches, which do not wait.
+    ``request_id`` numbers accepted requests (``None`` when shed at
+    submit), ``batch_id`` the server's batches; the profiler's
+    ``serve.batch`` span carries both. ``batch_occupancy`` is real
+    lanes / ``lane_width`` for lane batches, 1.0 for solo and update
+    batches. ``d2h_bytes`` counts the bytes the server copied from the
+    device for this request (a point-to-point lane's ``dist`` and
+    ``pred`` rows; results that stay on the device count 0)."""
 
     tenant: str
     kind: str
@@ -122,12 +149,10 @@ class RequestTrace:
     t_done: Optional[float] = None
     batch_occupancy: Optional[float] = None
     shed: Optional[str] = None
-
-    @property
-    def latency(self) -> Optional[float]:
-        if self.t_done is None:
-            return None
-        return self.t_done - self.t_submit
+    request_id: Optional[int] = None
+    batch_id: Optional[int] = None
+    t_ready: Optional[float] = None
+    d2h_bytes: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,6 +226,7 @@ class _Batch:
     tenant: _Tenant
     kind: str  # "lanes" | "solo" | "update"
     items: List[_Pending]
+    batch_id: int
 
 
 class Server:
@@ -247,6 +273,7 @@ class Server:
         self._work = threading.Condition(self._lock)
         self._tenants: Dict[str, _Tenant] = {}
         self._seq = 0
+        self._batch_seq = 0
         self._tick = 0
         self._thread: Optional[threading.Thread] = None
         self._closing = False
@@ -258,6 +285,7 @@ class Server:
         self._occupancy_sum = 0.0
         self._evictions = 0
         self._plans_built = 0
+        self._d2h_bytes = 0
         self._latencies = deque(maxlen=_LATENCY_WINDOW)
         if graphs is not None:
             if not isinstance(graphs, dict):
@@ -307,14 +335,16 @@ class Server:
         tenant.last_used = self._tick
         self._tick += 1
         if tenant.plan is None:
-            tenant.plan = Engine(
-                tenant.graph,
-                tenant.config,
-                free_mask=tenant.free_mask,
-                tuning=self._tuning,
-            ).plan(fallback=True)
-            if self._landmarks is not None:
-                tenant.plan.prepare_landmarks(**self._landmarks, build=False)
+            with TraceAnnotation("serve.plan_build", tenant=tenant.name):
+                tenant.plan = Engine(
+                    tenant.graph,
+                    tenant.config,
+                    free_mask=tenant.free_mask,
+                    tuning=self._tuning,
+                ).plan(fallback=True)
+                if self._landmarks is not None:
+                    tenant.plan.prepare_landmarks(**self._landmarks,
+                                                  build=False)
             self._plans_built += 1
             self._evict_locked(keep=tenant)
         return tenant.plan
@@ -348,6 +378,14 @@ class Server:
         the ticket with a typed ``RequestRejected`` instead of raising,
         so an open-loop generator never blocks on an overloaded server.
         """
+        with TraceAnnotation("serve.submit") as span:
+            ticket = self._submit(query, graph, deadline)
+            if ticket.trace.request_id is not None:
+                span.set_metadata(request_id=ticket.trace.request_id)
+            return ticket
+
+    def _submit(self, query, graph: Optional[str],
+                deadline: Optional[float]) -> Ticket:
         now = self._clock()
         with self._work:
             trace = RequestTrace(tenant=graph or "?",
@@ -370,6 +408,7 @@ class Server:
                     ticket, "queue_full",
                     f"queue depth {depth} at cap {self.max_queue}")
             self._seq += 1
+            trace.request_id = self._seq
             tenant.queue.append(_Pending(
                 seq=self._seq, query=query, ticket=ticket,
                 deadline=None if deadline is None else now + deadline))
@@ -443,26 +482,43 @@ class Server:
             kind, items = "solo", [tenant.queue.popleft()]
         t_batch = self._clock()
         occupancy = (len(items) / self.lane_width if kind == "lanes" else 1.0)
+        self._batch_seq += 1
         for p in items:
             p.ticket.trace.t_batch = t_batch
             p.ticket.trace.batch_occupancy = occupancy
+            p.ticket.trace.batch_id = self._batch_seq
         self._batches[kind] += 1
         self._occupancy_sum += occupancy
-        return _Batch(tenant=tenant, kind=kind, items=items)
+        return _Batch(tenant=tenant, kind=kind, items=items,
+                      batch_id=self._batch_seq)
 
     def pump(self) -> int:
         """Form and execute one microbatch inline (no worker thread);
         returns the number of requests resolved (0 = nothing queued)."""
         with self._lock:
-            batch = self._form_batch_locked()
-        if batch is None:
-            return 0
-        return self._execute(batch)
+            if not self._has_work_locked():
+                return 0
+        return self._serve_batch()
 
     def drain(self) -> None:
         """Serve inline until every queued request has resolved."""
         while self.pump():
             pass
+
+    def _serve_batch(self) -> int:
+        """Form one batch and execute it inside its ``serve.batch``
+        span; returns the number of requests resolved."""
+        with TraceAnnotation("serve.batch") as span:
+            with TraceAnnotation("serve.form_batch"):
+                with self._lock:
+                    batch = self._form_batch_locked()
+            if batch is None:
+                return 0
+            span.set_metadata(
+                batch_id=batch.batch_id, kind=batch.kind,
+                lanes=len(batch.items), tenant=batch.tenant.name,
+                requests=" ".join(str(p.seq) for p in batch.items))
+            return self._execute(batch)
 
     def _execute(self, batch: _Batch) -> int:
         try:
@@ -481,43 +537,50 @@ class Server:
         with self._lock:
             done = self._clock()
             for p in batch.items:
-                p.ticket.trace.t_done = done
-                if p.ticket.trace.shed is None and p.ticket.exception(0) is None:
+                trace = p.ticket.trace
+                trace.t_done = done
+                self._d2h_bytes += trace.d2h_bytes
+                if trace.shed is None and p.ticket.exception(0) is None:
                     self._completed += 1
                     batch.tenant.served += 1
-                    self._latencies.append(done - p.ticket.trace.t_submit)
+                    self._latencies.append(done - trace.t_submit)
         return len(batch.items)
 
     def _run_updates(self, batch: _Batch, plan) -> None:
         """Streamed update application between microbatches: weights
         swap on the owning plan, one request at a time so a refused
         update sheds its own ticket and the rest of the stream (and the
-        batch loop) keeps going."""
+        batch loop) keeps going. Each ticket resolves as its update
+        applies, inside the batch's one ``serve.dispatch`` span."""
         tenant = batch.tenant
-        for p in batch.items:
-            q = p.query
-            try:
-                plan.update(q.edge_ids, q.new_weights)
-            except UpdateRefused as e:
-                with self._lock:
-                    self._shed_locked(p.ticket, "update_refused", str(e))
-                continue
-            except ValueError as e:
-                with self._lock:
-                    self._shed_locked(p.ticket, "invalid", str(e))
-                continue
-            tenant.graph = plan.graph
-            p.ticket.trace.t_solve = self._clock()
-            if plan.explain()["resident_source"] is not None:
-                p.ticket._resolve(plan.resolve(warm=q.warm))
-            else:
-                p.ticket._resolve(
-                    UpdateApplied(n_edges=len(np.ravel(q.edge_ids))))
+        with TraceAnnotation("serve.dispatch"):
+            for p in batch.items:
+                q = p.query
+                try:
+                    plan.update(q.edge_ids, q.new_weights)
+                except UpdateRefused as e:
+                    with self._lock:
+                        self._shed_locked(p.ticket, "update_refused", str(e))
+                    continue
+                except ValueError as e:
+                    with self._lock:
+                        self._shed_locked(p.ticket, "invalid", str(e))
+                    continue
+                tenant.graph = plan.graph
+                p.ticket.trace.t_solve = self._clock()
+                if plan.explain()["resident_source"] is not None:
+                    p.ticket._resolve(plan.resolve(warm=q.warm))
+                else:
+                    p.ticket._resolve(
+                        UpdateApplied(n_edges=len(np.ravel(q.edge_ids))))
 
     def _run_solo(self, batch: _Batch, plan) -> None:
         (p,) = batch.items
-        p.ticket.trace.t_solve = self._clock()
-        p.ticket._resolve(plan.solve(p.query))
+        with TraceAnnotation("serve.dispatch"):
+            p.ticket.trace.t_solve = self._clock()
+            res = plan.solve(p.query)
+        with TraceAnnotation("serve.answer"):
+            p.ticket._resolve(res)
 
     def _run_lanes(self, batch: _Batch, plan) -> None:
         """One padded multi-source solve answers every lane: lane i is
@@ -527,12 +590,32 @@ class Server:
         items = batch.items
         sources = [int(p.query.source) for p in items]
         padded = sources + [sources[-1]] * (self.lane_width - len(sources))
-        res = plan.solve(MultiSource(np.asarray(padded, np.int32)))
+        with TraceAnnotation("serve.dispatch"):
+            res = plan.solve(MultiSource(np.asarray(padded, np.int32)))
         t_solve = self._clock()
+        with TraceAnnotation("serve.await_device"):
+            # the counters come back as the driver ends, the rows once
+            # pred_argmin has ended too
+            counters = tuple(np.asarray(c) for c in (
+                res.telemetry.buckets, res.telemetry.inner_iters,
+                res.telemetry.overflow))
+            jax.block_until_ready((res.dist, res.pred))
+        t_ready = self._clock()
+        for p in items:
+            p.ticket.trace.t_solve = t_solve
+            p.ticket.trace.t_ready = t_ready
+        with TraceAnnotation("serve.answer"):
+            self._answer_lanes(items, res, counters, plan)
+
+    def _answer_lanes(self, items: List[_Pending], res, counters,
+                      plan) -> None:
+        """Split a lane batch's result into its requests' answers
+        (``counters``: the batch's telemetry on the host). A
+        point-to-point lane copies its ``dist`` row and, when the target
+        is reached, its ``pred`` row to the host and walks the path
+        there; the other lane kinds keep their rows on the device."""
         dist, pred = res.dist, res.pred
-        outer = np.asarray(res.telemetry.buckets)
-        inner = np.asarray(res.telemetry.inner_iters)
-        over = np.asarray(res.telemetry.overflow)
+        outer, inner, over = counters
 
         def lane(arr, i):
             return arr[i] if arr.ndim else arr
@@ -546,15 +629,22 @@ class Server:
                 overflow=lane(over, i),
                 fallback=res.telemetry.fallback,
             )
-            p.ticket.trace.t_solve = t_solve
             if isinstance(q, SingleSource):
                 p.ticket._resolve(SingleSourceResult(dist[i], pred[i], tel))
             elif isinstance(q, PointToPoint):
-                distance = int(np.asarray(dist[i])[int(q.target)])
+                with TraceAnnotation("serve.copy_rows"):
+                    row = np.asarray(dist[i])
+                    distance = int(row[int(q.target)])
+                    rows = [row]
+                    if (distance < int(INF32)
+                            and plan.config.pred_mode != "none"):
+                        rows.append(np.asarray(pred[i]))
+                p.ticket.trace.d2h_bytes = sum(r.nbytes for r in rows)
                 path = None
-                if distance < int(INF32) and plan.config.pred_mode != "none":
-                    path = extract_path(
-                        np.asarray(pred[i]), int(q.source), int(q.target), n)
+                if len(rows) == 2:
+                    with TraceAnnotation("serve.extract_path"):
+                        path = extract_path(
+                            rows[1], int(q.source), int(q.target), n)
                 p.ticket._resolve(PointToPointResult(distance, path, tel))
             else:  # BoundedRadius: the full lane filtered to the radius
                 within = dist[i] <= q.radius
@@ -576,14 +666,13 @@ class Server:
 
     def _loop(self) -> None:
         while True:
-            with self._work:
-                while not self._closing and not self._has_work_locked():
-                    self._work.wait()
-                if self._closing and not self._has_work_locked():
-                    return
-                batch = self._form_batch_locked()
-            if batch is not None:
-                self._execute(batch)
+            with TraceAnnotation("serve.wait_work"):
+                with self._work:
+                    while not self._closing and not self._has_work_locked():
+                        self._work.wait()
+                    if self._closing and not self._has_work_locked():
+                        return
+            self._serve_batch()
 
     def close(self, drain: bool = True) -> None:
         """Stop serving. ``drain=True`` (default) answers everything
@@ -615,9 +704,14 @@ class Server:
     def stats(self) -> dict:
         """Aggregated serving telemetry: request accounting (submitted /
         completed / shed-by-reason / queued), batch counts and mean
-        occupancy, tenancy state (resident plans, builds, evictions) and
-        completed-request latency percentiles in milliseconds (over the
-        last {window} requests).""".format(window=_LATENCY_WINDOW)
+        occupancy, tenancy state (resident plans, builds, evictions),
+        bytes copied from the device to the host (``d2h_bytes``, the sum
+        of every request's ``RequestTrace.d2h_bytes``) and percentiles
+        of ``t_done - t_submit`` in milliseconds over the last {window}
+        completed requests. ``t_done`` of a solo ``MultiSource`` is when
+        its dispatch returned, before its rows are ready on the device,
+        so its latency leaves the device solve out.""".format(
+            window=_LATENCY_WINDOW)
         with self._lock:
             lat = sorted(self._latencies)
 
@@ -640,6 +734,7 @@ class Server:
                     if t.plan is not None),
                 "plans_built": self._plans_built,
                 "evictions": self._evictions,
+                "d2h_bytes": self._d2h_bytes,
                 "per_tenant": {
                     t.name: t.served for t in self._tenants.values()},
                 "latency_p50_ms": pct(0.50),

@@ -78,7 +78,8 @@ def test_server_surface():
         assert hasattr(serve.Ticket, attr), attr
     assert [f for f in serve.RequestTrace.__dataclass_fields__] == [
         "tenant", "kind", "t_submit", "t_batch", "t_solve", "t_done",
-        "batch_occupancy", "shed"]
+        "batch_occupancy", "shed", "request_id", "batch_id", "t_ready",
+        "d2h_bytes"]
 
 
 def test_deprecated_aliases_still_exported():
