@@ -406,8 +406,17 @@ class Plan:
         batch. Rebuilds pin the weight-independent full adjacency
         degree instead: the first update may recompile once (wider
         shapes than the plan-time build), every later one reuses it.
+        The ``edge`` strategy's light/heavy halves have the same
+        problem in their lengths; rebuilds pad both to capacity m (an
+        updated plan sweeps m slots per phase, the unsplit cost).
         ``sharded_ell`` keeps the default partition build and may
         retrace when the split widths move."""
+        if self.config.strategy == "edge":
+            from repro.core.backends import EdgeBackend
+
+            return EdgeBackend.build(
+                self.graph, self.config, capacity=self.graph.n_edges
+            )
         if self.config.strategy == "ell":
             from repro.core.backends import EllBackend
 
@@ -620,12 +629,17 @@ class Plan:
         point plus the tuning record (if any) it came from.
         ``kernel_path`` says how the strategy's Pallas kernels run:
         'compiled', 'interpret', 'twin', or 'xla' for a strategy
-        without kernels (core.backends ``kernel_path``)."""
+        without kernels (core.backends ``kernel_path``). ``edge_split``
+        gives an ``edge`` plan's light and heavy edge counts and the
+        capacity its halves are padded to (``EdgeBackend.edge_split``);
+        None for other strategies."""
         cfg = self.config
+        split = getattr(self.backend, "edge_split", None)
         return {
             "delta": cfg.delta,
             "strategy": cfg.strategy,
             "kernel_path": self.backend.kernel_path,
+            "edge_split": None if split is None else split(),
             "policy": cfg.policy,
             "pred_mode": cfg.pred_mode,
             "frontier_cap": cfg.frontier_cap,

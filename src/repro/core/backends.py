@@ -5,8 +5,8 @@ DESIGN.md §3: the paper's three inner mechanisms — request generation
 isolated behind the ``RelaxBackend`` protocol so a single generic
 outer/inner loop driver (``core.delta_stepping``) hosts every strategy:
 
-* ``edge``   — edge-centric |E| sweep (jnp scatter-min), zero
-  preprocessing; the light mask is evaluated on the fly.
+* ``edge``   — edge-centric sweep (jnp scatter-min) over the edge
+  array of the phase: light and heavy halves split once at build.
 * ``ell``    — frontier-compacted expansion of light/heavy ELL blocks
   (jnp); work scales with |frontier|·max_deg.
 * ``pallas`` — the same ELL expansion through the ``kernels/ell_relax``
@@ -57,7 +57,8 @@ grids has no batching rule).
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from functools import partial
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -352,26 +353,76 @@ def _ell_blocks(graph: COOGraph, delta: int, max_deg=None):
     return csr_to_ell(light, max_deg), csr_to_ell(heavy, max_deg)
 
 
+@partial(jax.jit, static_argnames=("delta", "n", "n_light", "n_heavy"))
+def _split_edges(src, dst, w, *, delta: int, n: int, n_light: int,
+                 n_heavy: int):
+    """Stable light/heavy partition of an edge array on the device
+    (paper Alg. 1 lines 3–5): the light half (``w <= delta``) and the
+    heavy half, each in the original edge order, of ``n_light`` and
+    ``n_heavy`` slots. Slots past a half's edges are sentinel edges
+    (``src = dst = n``, ``w = INF32``), which every sweep's gathers fill
+    inactive and its scatter drops."""
+    light = w <= delta
+    m = w.shape[0]
+
+    def half(mask, size):
+        idx = jnp.nonzero(mask, size=size, fill_value=m)[0]
+        return (jnp.take(src, idx, mode="fill", fill_value=n),
+                jnp.take(dst, idx, mode="fill", fill_value=n),
+                jnp.take(w, idx, mode="fill", fill_value=INF32))
+
+    return half(light, n_light) + half(~light, n_heavy)
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class EdgeBackend(RelaxBackend):
-    """Edge-centric strategy: every sweep touches all |E| edges, masked
-    by frontier membership of their source (fixed shapes, no compaction)."""
+    """Edge-centric strategy: the edges are split once at build into a
+    light half (``w <= delta``) and a heavy half, and each sweep touches
+    every edge of its phase, masked by frontier membership of their
+    source (fixed shapes, no compaction). ``capacity`` is the slot count
+    both halves are padded to with sentinel edges (``Plan.update``
+    rebuilds at capacity m, so weight churn keeps the compiled shapes);
+    None sizes each half to its edges exactly."""
 
-    src: jax.Array
-    dst: jax.Array
-    w: jax.Array
+    src_l: jax.Array
+    dst_l: jax.Array
+    w_l: jax.Array
+    src_h: jax.Array
+    dst_h: jax.Array
+    w_h: jax.Array
     delta: int = _static()
+    n: int = _static()
     canonical: bool = _static()
+    capacity: Optional[int] = _static()
 
     @classmethod
-    def build(cls, graph: COOGraph, cfg) -> "EdgeBackend":
-        return cls(graph.src, graph.dst, graph.w, cfg.delta,
-                   graph_is_canonical(graph))
+    def build(cls, graph: COOGraph, cfg, capacity=None) -> "EdgeBackend":
+        w = jnp.asarray(graph.w)
+        if capacity is None:
+            n_light = int(jnp.sum(w <= cfg.delta))   # fixes the shapes
+            sizes = (n_light, graph.n_edges - n_light)
+        else:
+            sizes = (capacity, capacity)
+        halves = _split_edges(jnp.asarray(graph.src), jnp.asarray(graph.dst),
+                              w, delta=cfg.delta, n=graph.n_nodes,
+                              n_light=sizes[0], n_heavy=sizes[1])
+        return cls(*halves, cfg.delta, graph.n_nodes,
+                   graph_is_canonical(graph), capacity)
+
+    def edge_split(self) -> dict:
+        """Edges in each half — what each phase's sweep relaxes; sentinel
+        slots have ``src == n`` — and the slots both are padded to (None:
+        each half holds exactly its edges)."""
+        return {"light": int(jnp.sum(self.src_l < self.n)),
+                "heavy": int(jnp.sum(self.src_h < self.n)),
+                "capacity": self.capacity}
 
     @jax.named_scope("edge_sweep")
     def sweep(self, tent, mask, bucket_i, *, light: bool, packed: bool):
-        tent = edge_sweep(tent, mask, self.src, self.dst, self.w,
+        src, dst, w = ((self.src_l, self.dst_l, self.w_l) if light
+                       else (self.src_h, self.dst_h, self.w_h))
+        tent = edge_sweep(tent, mask, src, dst, w,
                           delta=self.delta, light=light, packed=packed,
                           canonical=self.canonical)
         return tent, jnp.zeros((), bool)

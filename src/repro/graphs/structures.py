@@ -152,8 +152,9 @@ def union_with_reverse(g: COOGraph) -> COOGraph:
 
 def light_heavy_split(g: CSRGraph, delta: int) -> Tuple[CSRGraph, CSRGraph]:
     """Paper Alg. 1 lines 3–5: split outgoing edges into light (w <= Δ) and
-    heavy (w > Δ) CSR structures. Host-side preprocessing; the edge-centric
-    jit path instead evaluates the mask on the fly (DESIGN.md §2)."""
+    heavy (w > Δ) CSR structures. Host-side preprocessing for the ELL
+    strategies; the edge-centric path splits its COO arrays on the device
+    instead (``core.backends.EdgeBackend``, DESIGN.md §3)."""
     row_ptr = np.asarray(g.row_ptr)
     col = np.asarray(g.col)
     w = np.asarray(g.w)
